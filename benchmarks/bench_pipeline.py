@@ -51,7 +51,7 @@ import sys
 from repro import obs
 from repro.roofline.analysis import overlap_speedup_bound
 
-from .common import Row
+from .common import Row, refuse_on_tpu
 
 S = 8
 
@@ -300,6 +300,7 @@ def _run_child(child_cfg: dict, devices: int = S) -> dict:
     """Run the measured section in a fresh process with forced host
     devices and single-threaded BLAS (the parent's backend is already
     initialized single-device)."""
+    refuse_on_tpu("bench_pipeline")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={devices}")

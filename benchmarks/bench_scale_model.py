@@ -41,7 +41,7 @@ from repro.core.layout import DHTConfig, dht_create
 from repro.obs import costmodel
 from repro.obs import trace as obs_trace
 
-from .common import Row, make_keys_vals, time_fn
+from .common import Row, make_keys_vals, refuse_on_tpu, time_fn
 
 KW, VW = 8, 8          # compact lanes: the model is lane-width-aware
 BPS = 512              # buckets per shard in the sweep tables
@@ -155,6 +155,7 @@ print(json.dumps({"hlo_words": hlo_words, "engine_words": engine_words,
 def _wire_hlo_xcheck(devices: int = 4) -> dict:
     """Run the wire-vs-HLO audit in a fresh subprocess with forced host
     devices (the parent's jax backend is already initialized)."""
+    refuse_on_tpu("bench_scale_model")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={devices}")

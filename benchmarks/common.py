@@ -24,6 +24,17 @@ SW_OVERHEAD = 3.8e-5
 PAPER_RANKS = 640
 
 
+def refuse_on_tpu(bench: str) -> None:
+    """Stop a CPU rehearsal bench that would start a CPU-forced child on
+    a TPU host: the parent would hold the chip while the child measured
+    the CPU, under the chip's name."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{bench} is a CPU rehearsal (its measured child runs on forced "
+            "host devices); run it with JAX_PLATFORMS=cpu, or use "
+            "chip_smoke.py on the chip")
+
+
 @dataclasses.dataclass
 class Row:
     name: str

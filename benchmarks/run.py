@@ -52,6 +52,9 @@ def main() -> None:
     repeats = max(args.repeats, 1)
 
     from repro import obs
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     from . import (
         bench_crash,

@@ -1,26 +1,27 @@
-"""Version compatibility shims for the JAX API surface we depend on.
+"""Thin wrappers over the JAX mesh and ``shard_map`` API this repo uses.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` (kwarg
-``check_rep``) to ``jax.shard_map`` (kwarg ``check_vma``); we support
-both so the sharded DHT backend runs on the full range of jax versions
-the container images carry.
+``make_mesh`` pins every axis to ``AxisType.Auto``: ``jax.make_mesh``
+defaults to ``Explicit`` axes, under which the host-side gathers of the
+sharded backend (membership, crash/repair, the L1 paths) raise
+``ShardingTypeError``.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType, Mesh
 
-try:  # jax >= 0.6: public API
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-except AttributeError:  # jax <= 0.5: experimental API
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    _CHECK_KW = "check_rep"
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str],
+              devices=None) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` with replication checking toggled portably."""
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: check},
-    )
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -14,13 +14,13 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from . import dht as dht_ops
 from . import l1cache
-from .compat import shard_map
+from .compat import make_mesh, shard_map
 from .layout import DHTConfig, DHTState, dht_create
 from .pipeline import RoundQueue
 
@@ -137,6 +137,12 @@ class ShardedDHT:
     @classmethod
     def create(cls, mesh: Mesh, cfg: DHTConfig, ring=None,
                l1cfg: l1cache.L1Config | None = None) -> "ShardedDHT":
+        if AxisType.Explicit in mesh.axis_types:
+            raise ValueError(
+                "ShardedDHT needs a mesh with Auto axes (got "
+                f"{mesh.axis_types}); build it with make_mesh_1d or "
+                "repro.core.compat.make_mesh — the host-side gathers of "
+                "membership, repair and the L1 paths fail on Explicit axes")
         n_dev = mesh.devices.size
         assert cfg.n_shards == n_dev, (
             f"one shard per device: n_shards={cfg.n_shards} != mesh size {n_dev}"
@@ -223,7 +229,10 @@ class ShardedDHT:
         ``("read",)``/``("write",)`` mirror :meth:`read_fn`/:meth:`write_fn`.
 
         The returned closure maps ``(state, keys, vals, valid) ->
-        (state', vals, found, code, estats)``."""
+        (state', vals, found, code, estats)``.  With more than one kind
+        (e.g. ``("read", "write")``) the batch is op-tagged and the
+        closure takes the per-item ``OP_*`` tag as a fifth argument,
+        ``op``."""
         assert self.l1 is None or "write" not in kinds, (
             "L1 attached: a same-epoch write round without the watermark "
             "refresh would let stale cached lines keep serving — use "
@@ -232,10 +241,12 @@ class ShardedDHT:
             "the cache via the epoch stamp.)")
         axes, state_spec, batch_spec = self._specs(state)
         do_write = ("write" in kinds) or ("migrate" in kinds)
+        tagged = len(kinds) > 1
 
-        def fn(state, keys, vals, valid):
+        def fn(state, keys, vals, valid, *op):
             ops = dht_ops.OpBatch(
-                keys=keys, valid=valid, vals=vals if do_write else None)
+                keys=keys, valid=valid, vals=vals if do_write else None,
+                op=op[0].astype(jnp.int32) if tagged else None)
             state, _, out, found, code, es = dht_ops.dht_execute(
                 state, ops, kinds=kinds, axis_name=axes)
             return state, out, found, code, _psum_stats(es, axes)
@@ -249,7 +260,8 @@ class ShardedDHT:
         return jax.jit(
             shard_map(
                 fn, mesh=self.mesh,
-                in_specs=(state_spec, batch_spec, batch_spec, batch_spec),
+                in_specs=(state_spec, batch_spec, batch_spec, batch_spec)
+                + (batch_spec,) * tagged,
                 out_specs=(state_spec, batch_spec, batch_spec, batch_spec,
                            stats_spec),
             )
@@ -856,6 +868,8 @@ class ShardedDHT:
 
 
 def make_mesh_1d(n: int | None = None, name: str = "dht") -> Mesh:
+    """One-axis ``Auto`` mesh over the first ``n`` devices (all by
+    default) — one DHT shard per device."""
     devs = jax.devices()
     n = n or len(devs)
-    return jax.make_mesh((n,), (name,))
+    return make_mesh((n,), (name,), devices=devs[:n])

@@ -17,7 +17,10 @@ replacing the per-payload ``buf.at[slot].set`` / fancy-gather loops:
 Same TPU idiom as ``apply_kernel``: the per-row indirection arrays are
 scalar-prefetched to SMEM and drive the BlockSpec index maps
 (``PrefetchScalarGridSpec``), so the DMA for row i+1 overlaps row i's
-select/store; one grid step touches one (1, L) lane row.  Validated
+select/store; one grid step touches one (1, L) lane row.  Mosaic tiles
+the last two dims of a block, and a (1, L) block of an (n, L) array is
+neither (8, 128)-aligned nor the full array, so the matrices ride a
+squeezed leading row axis: array (n, 1, L), block (None, 1, L).  Validated
 bit-for-bit against ``kernels/ref.ref_route_pack``/``ref_route_unpack``
 (pinned to the production jnp path in ``core/routing.py``).
 """
@@ -62,18 +65,20 @@ def route_pack_pallas(
         num_scalar_prefetch=1,
         grid=(rows,),
         in_specs=[
-            pl.BlockSpec((1, width),
-                         lambda i, inv_ref: (jnp.maximum(inv_ref[i], 0), 0)),
+            pl.BlockSpec((None, 1, width),
+                         lambda i, inv_ref: (jnp.maximum(inv_ref[i], 0), 0, 0)),
             pl.BlockSpec((1, width), lambda i, inv_ref: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, width), lambda i, inv_ref: (i, 0)),
+        out_specs=pl.BlockSpec((None, 1, width),
+                               lambda i, inv_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _pack_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, width), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((rows, 1, width), jnp.uint32),
         interpret=interpret,
-    )(inv, mat, fill_row.reshape(1, width))
+    )(inv, mat.reshape(n, 1, width), fill_row.reshape(1, width))
+    return out.reshape(rows, width)
 
 
 def _unpack_kernel(slot_ref,  # scalar prefetch: (n,) int32 bin row per item
@@ -109,16 +114,17 @@ def route_unpack_pallas(
         num_scalar_prefetch=2,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, width),
-                         lambda i, slot_ref, kept_ref: (slot_ref[i], 0)),
+            pl.BlockSpec((None, 1, width),
+                         lambda i, slot_ref, kept_ref: (slot_ref[i], 0, 0)),
             pl.BlockSpec((1, width), lambda i, slot_ref, kept_ref: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, width),
-                               lambda i, slot_ref, kept_ref: (i, 0)),
+        out_specs=pl.BlockSpec((None, 1, width),
+                               lambda i, slot_ref, kept_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _unpack_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, width), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((n, 1, width), jnp.uint32),
         interpret=interpret,
-    )(slot, kept, buf, fill_row.reshape(1, width))
+    )(slot, kept, buf.reshape(rows, 1, width), fill_row.reshape(1, width))
+    return out.reshape(n, width)

@@ -2,7 +2,7 @@
 touches jax device state)."""
 from __future__ import annotations
 
-import jax
+from ..core.compat import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -10,7 +10,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

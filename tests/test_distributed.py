@@ -23,9 +23,10 @@ def test_sharded_dht_all_modes():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig
+        from repro.core.compat import make_mesh
         from repro.core.distributed import ShardedDHT
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         keys = jnp.asarray(rng.integers(0, 2**31, size=(256, 20)), jnp.uint32)
         vals = jnp.asarray(rng.integers(0, 2**31, size=(256, 26)), jnp.uint32)
@@ -48,9 +49,9 @@ def test_sharded_dht_read_many_one_round():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig
-        from repro.core.distributed import ShardedDHT
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
 
-        mesh = jax.make_mesh((8,), ("dht",))
+        mesh = make_mesh_1d(8)
         rng = np.random.default_rng(1)
         keys = jnp.asarray(rng.integers(0, 2**31, size=(256, 20)), jnp.uint32)
         vals = jnp.asarray(rng.integers(0, 2**31, size=(256, 26)), jnp.uint32)
@@ -79,9 +80,9 @@ def test_sharded_execute_fn_matches_wrappers_all_modes():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig
         from repro.core.dht import W_SKIP, W_INSERT
-        from repro.core.distributed import ShardedDHT
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
 
-        mesh = jax.make_mesh((8,), ("dht",))
+        mesh = make_mesh_1d(8)
         rng = np.random.default_rng(5)
         keys = jnp.asarray(rng.integers(0, 2**31, size=(256, 20)), jnp.uint32)
         vals = jnp.asarray(rng.integers(0, 2**31, size=(256, 26)), jnp.uint32)
@@ -141,9 +142,9 @@ def test_sharded_l1_locality_tier_parity_and_elision():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig, L1Config
-        from repro.core.distributed import ShardedDHT
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
 
-        mesh = jax.make_mesh((8,), ("dht",))
+        mesh = make_mesh_1d(8)
         rng = np.random.default_rng(7)
         keys = jnp.asarray(rng.integers(0, 2**31, size=(256, 20)), jnp.uint32)
         vals = jnp.asarray(rng.integers(0, 2**31, size=(256, 26)), jnp.uint32)
@@ -206,9 +207,9 @@ def test_sharded_telemetry_parity_and_merge():
         import jax, jax.numpy as jnp, numpy as np
         from repro import obs
         from repro.core import DHTConfig
-        from repro.core.distributed import ShardedDHT
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
 
-        mesh = jax.make_mesh((8,), ("dht",))
+        mesh = make_mesh_1d(8)
         rng = np.random.default_rng(5)
         keys = jnp.asarray(rng.integers(0, 2**31, size=(256, 20)), jnp.uint32)
         vals = jnp.asarray(rng.integers(0, 2**31, size=(256, 26)), jnp.uint32)
@@ -244,6 +245,7 @@ def test_sharded_train_step_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config, reduced
+        from repro.core.compat import make_mesh
         from repro.optim import AdamWConfig
         from repro.train import make_train_state, make_train_step
         from repro.launch.shardings import batch_shardings, params_shardings
@@ -257,7 +259,7 @@ def test_sharded_train_step_matches_single_device():
         step = make_train_step(cfg, AdamWConfig(), donate=False)
         _, _, m1 = step(params, opt, batch)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         p_sh = params_shardings(jax.eval_shape(lambda: params), mesh)
         b_sh = batch_shardings(jax.eval_shape(lambda: batch), mesh)
         params_d = jax.device_put(params, p_sh)
@@ -279,6 +281,7 @@ def test_elastic_restart_across_meshes():
         import tempfile, jax, jax.numpy as jnp, numpy as np
         from repro.checkpoint import restore, save
         from repro.configs import get_config, reduced
+        from repro.core.compat import make_mesh
         from repro.launch.shardings import params_shardings
         from repro.optim import AdamWConfig
         from repro.train import make_train_state, make_train_step
@@ -288,7 +291,7 @@ def test_elastic_restart_across_meshes():
         with tempfile.TemporaryDirectory() as d:
             save(d, 3, (params, opt))
             # "restart" onto a 8-device mesh: restore + apply new shardings
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             step, (p2, o2) = restore(d, (params, opt))
             p_sh = params_shardings(jax.eval_shape(lambda: p2), mesh)
             p2 = jax.device_put(p2, p_sh)

@@ -353,10 +353,10 @@ def test_sharded_crash_failover_repair():
     _run_sharded("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig, ring_create
-        from repro.core.distributed import ShardedDHT
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
         from repro.obs import metrics as obs_metrics
 
-        mesh = jax.make_mesh((8,), ("dht",))
+        mesh = make_mesh_1d(8)
         rng = np.random.default_rng(0)
         keys = jnp.asarray(rng.integers(0, 2**31, size=(1024, 20)),
                            jnp.uint32)
@@ -393,9 +393,9 @@ def test_sharded_l1_crash_fence():
     _run_sharded("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig, L1Config, ring_create
-        from repro.core.distributed import ShardedDHT
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
 
-        mesh = jax.make_mesh((8,), ("dht",))
+        mesh = make_mesh_1d(8)
         rng = np.random.default_rng(1)
         keys = jnp.asarray(rng.integers(0, 2**31, size=(512, 20)),
                            jnp.uint32)
@@ -428,10 +428,10 @@ def test_sharded_write_retry_on_overflow():
     _run_sharded("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig, ring_create
-        from repro.core.distributed import ShardedDHT
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
         from repro.obs import metrics as obs_metrics
 
-        mesh = jax.make_mesh((8,), ("dht",))
+        mesh = make_mesh_1d(8)
         rng = np.random.default_rng(2)
         n = 2048
         keys = jnp.asarray(rng.integers(0, 2**31, size=(n, 20)), jnp.uint32)

@@ -278,11 +278,11 @@ def test_sharded_leave_join_all_to_all():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig, ring_create
-        from repro.core.distributed import ShardedDHT
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
         from repro.core.layout import occupancy
 
         assert len(jax.devices()) >= 2
-        mesh = jax.make_mesh((8,), ("dht",))
+        mesh = make_mesh_1d(8)
         rng = np.random.default_rng(0)
         keys = jnp.asarray(rng.integers(0, 2**31, size=(256, 20)), jnp.uint32)
         vals = jnp.asarray(rng.integers(0, 2**31, size=(256, 26)), jnp.uint32)

@@ -341,9 +341,9 @@ def test_sharded_async_closures_never_alias_sync():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig
-        from repro.core.distributed import ShardedDHT
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
 
-        mesh = jax.make_mesh((4,), ("d",))
+        mesh = make_mesh_1d(4, "d")
         d = ShardedDHT.create(mesh, DHTConfig(
             n_shards=4, buckets_per_shard=512))
         rng = np.random.default_rng(0)
@@ -372,12 +372,13 @@ def test_sharded_pipelined_parity_l1_on_and_off():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core import DHTConfig, L1Config
-        from repro.core.distributed import ShardedDHT, _state_shardings
+        from repro.core.distributed import (
+            ShardedDHT, _state_shardings, make_mesh_1d)
         from repro.core.layout import dht_create
         from repro.core.pipeline import PendingWrites, RoundQueue
 
         KW, VW = 20, 26
-        mesh = jax.make_mesh((4,), ("d",))
+        mesh = make_mesh_1d(4, "d")
         cfg = DHTConfig(n_shards=4, buckets_per_shard=1024)
         rng = np.random.default_rng(1)
 
