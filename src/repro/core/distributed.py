@@ -516,78 +516,92 @@ class ShardedDHT:
         a silently dropped insert is a lost acked write).  Only the FINAL
         round's unrecovered drops stay on the ``dropped``/
         ``engine.dropped`` lanes; recovered rows count as ``requeued``."""
-        valid = self._ones(keys.shape[0]) if valid is None else valid
-        n_ops = int(keys.shape[0])
-        total = None
-        attempt = 0
-        while True:
-            t_a = time.perf_counter()
-            stats = self._write_dispatch(keys, vals, valid)
-            code = stats["code"]
-            retry = valid & (code == dht_ops.W_DROPPED)
-            n_retry = int(jnp.sum(retry))
-            final = n_retry == 0 or attempt >= max_retries
-            flush = dict(stats)
-            if not final:
-                # this round's drops are about to be re-issued — flush
-                # them as requeued so engine.dropped keeps meaning
-                # "lost for good" (what the CI ratio gate measures)
-                flush["requeued"] = flush.pop("dropped")
-            obs_trace.record_round("sharded.write", flush,
-                                   ops={"write": n_ops}, t_start=t_a)
-            if total is None:
-                total = dict(stats)
-            else:
-                for lane in ("inserted", "updated", "evicted", "acked",
-                             "replica_writes", "lock_tokens", "wire_words",
-                             "rounds"):
-                    if lane in total:
-                        total[lane] = total[lane] + stats[lane]
-                # a retried row's fresh outcome overrides its drop code
-                total["code"] = jnp.where(code != dht_ops.W_DROPPED,
-                                          code, total["code"])
-                total["dropped"] = stats["dropped"]
-            if final:
-                total["write_retries"] = jnp.int32(attempt)
-                return total
-            attempt += 1
-            n_ops = n_retry
-            valid = retry
+        with obs_trace.span("dht.write"):
+            valid = self._ones(keys.shape[0]) if valid is None else valid
+            n_ops = int(keys.shape[0])
+            total = None
+            attempt = 0
+            while True:
+                t_a = time.perf_counter()
+                with obs_trace.span("dht.dispatch"):
+                    stats = self._write_dispatch(keys, vals, valid)
+                code = stats["code"]
+                with obs_trace.span("dht.retry_check"):
+                    retry = valid & (code == dht_ops.W_DROPPED)
+                    n_retry = int(jnp.sum(retry))
+                final = n_retry == 0 or attempt >= max_retries
+                flush = dict(stats)
+                if not final:
+                    # this round's drops are about to be re-issued — flush
+                    # them as requeued so engine.dropped keeps meaning
+                    # "lost for good" (what the CI ratio gate measures)
+                    flush["requeued"] = flush.pop("dropped")
+                with obs_trace.span("dht.flush"):
+                    obs_trace.record_round("sharded.write", flush,
+                                           ops={"write": n_ops}, t_start=t_a)
+                if total is None:
+                    total = dict(stats)
+                else:
+                    for lane in ("inserted", "updated", "evicted", "acked",
+                                 "replica_writes", "lock_tokens",
+                                 "wire_words", "rounds"):
+                        if lane in total:
+                            total[lane] = total[lane] + stats[lane]
+                    # a retried row's fresh outcome overrides its drop code
+                    total["code"] = jnp.where(code != dht_ops.W_DROPPED,
+                                              code, total["code"])
+                    total["dropped"] = stats["dropped"]
+                if final:
+                    total["write_retries"] = jnp.int32(attempt)
+                    return total
+                attempt += 1
+                n_ops = n_retry
+                valid = retry
 
     def read(self, keys, valid=None):
-        t0 = time.perf_counter()
-        valid = self._ones(keys.shape[0]) if valid is None else valid
-        if self.l1 is not None:
-            fn = self._cached_fn("read_cached", self.read_cached_fn,
-                                 extra=(self.l1cfg,))
-            self.state, self.l1, vals, found, stats = fn(
-                self.state, self.l1, keys, valid)
-            source = "sharded.read_cached"
-        else:
-            fn = self._cached_fn("read", self.read_fn)
-            self.state, vals, found, stats = fn(self.state, keys, valid)
-            source = "sharded.read"
-        obs_trace.record_round(source, stats,
-                               ops={"read": int(keys.shape[0])}, t_start=t0)
-        return vals, found, stats
+        with obs_trace.span("dht.read"):
+            t0 = time.perf_counter()
+            valid = self._ones(keys.shape[0]) if valid is None else valid
+            with obs_trace.span("dht.dispatch"):
+                if self.l1 is not None:
+                    fn = self._cached_fn("read_cached", self.read_cached_fn,
+                                         extra=(self.l1cfg,))
+                    self.state, self.l1, vals, found, stats = fn(
+                        self.state, self.l1, keys, valid)
+                    source = "sharded.read_cached"
+                else:
+                    fn = self._cached_fn("read", self.read_fn)
+                    self.state, vals, found, stats = fn(self.state, keys,
+                                                        valid)
+                    source = "sharded.read"
+            with obs_trace.span("dht.flush"):
+                obs_trace.record_round(source, stats,
+                                       ops={"read": int(keys.shape[0])},
+                                       t_start=t0)
+            return vals, found, stats
 
     def read_many(self, keys, valid=None):
-        t0 = time.perf_counter()
-        if valid is None:
-            valid = self._ones(keys.shape[:2])
-        if self.l1 is not None:
-            fn = self._cached_fn("read_many_refresh",
-                                 self.read_many_refresh_fn,
-                                 extra=(self.l1cfg,))
-            self.state, self.l1, vals, found, stats = fn(
-                self.state, self.l1, keys, valid)
-        else:
-            fn = self._cached_fn("read_many", self.read_many_fn)
-            self.state, vals, found, stats = fn(self.state, keys, valid)
-        obs_trace.record_round(
-            "sharded.read_many", stats,
-            ops={"read": int(keys.shape[0] * keys.shape[1])}, t_start=t0)
-        return vals, found, stats
+        with obs_trace.span("dht.read_many"):
+            t0 = time.perf_counter()
+            if valid is None:
+                valid = self._ones(keys.shape[:2])
+            with obs_trace.span("dht.dispatch"):
+                if self.l1 is not None:
+                    fn = self._cached_fn("read_many_refresh",
+                                         self.read_many_refresh_fn,
+                                         extra=(self.l1cfg,))
+                    self.state, self.l1, vals, found, stats = fn(
+                        self.state, self.l1, keys, valid)
+                else:
+                    fn = self._cached_fn("read_many", self.read_many_fn)
+                    self.state, vals, found, stats = fn(self.state, keys,
+                                                        valid)
+            with obs_trace.span("dht.flush"):
+                obs_trace.record_round(
+                    "sharded.read_many", stats,
+                    ops={"read": int(keys.shape[0] * keys.shape[1])},
+                    t_start=t0)
+            return vals, found, stats
 
     # -- issue/commit pipelined wrappers (DESIGN.md §12) -------------------
     # The jitted closures are asynchronous already — a call returns device
@@ -603,17 +617,18 @@ class ShardedDHT:
         in flight (use :meth:`round_queue`)."""
         t0 = time.perf_counter()
         valid = self._ones(keys.shape[0]) if valid is None else valid
-        if self.l1 is not None:
-            fn = self._cached_fn("read_cached", self.read_cached_fn,
-                                 extra=(self.l1cfg,) + self._async_key())
-            self.state, self.l1, vals, found, stats = fn(
-                self.state, self.l1, keys, valid)
-            source = "sharded.read_cached"
-        else:
-            fn = self._cached_fn("read", self.read_fn,
-                                 extra=self._async_key())
-            self.state, vals, found, stats = fn(self.state, keys, valid)
-            source = "sharded.read"
+        with obs_trace.span("dht.dispatch"):
+            if self.l1 is not None:
+                fn = self._cached_fn("read_cached", self.read_cached_fn,
+                                     extra=(self.l1cfg,) + self._async_key())
+                self.state, self.l1, vals, found, stats = fn(
+                    self.state, self.l1, keys, valid)
+                source = "sharded.read_cached"
+            else:
+                fn = self._cached_fn("read", self.read_fn,
+                                     extra=self._async_key())
+                self.state, vals, found, stats = fn(self.state, keys, valid)
+                source = "sharded.read"
         return ShardedRound(source=source, outs=(vals, found), stats=stats,
                             ops={"read": int(keys.shape[0])}, t_start=t0,
                             t_issued=time.perf_counter())
@@ -623,8 +638,9 @@ class ShardedDHT:
         :meth:`write_commit`."""
         t0 = time.perf_counter()
         valid = self._ones(keys.shape[0]) if valid is None else valid
-        stats = self._write_dispatch(keys, vals, valid,
-                                     extra=self._async_key())
+        with obs_trace.span("dht.dispatch"):
+            stats = self._write_dispatch(keys, vals, valid,
+                                         extra=self._async_key())
         # no retry loop here — it would force a mid-pipeline fetch; the
         # pipelined caller re-issues dropped rows itself (the surrogate
         # driver retires only non-dropped keys from its PendingWrites)
@@ -646,10 +662,11 @@ class ShardedDHT:
         stats["hidden_us"] = hidden * 1e6
         stats["commit_wait_us"] = max(now - t_commit, 0.0) * 1e6
         stats["overlap_frac"] = min(hidden / dur, 1.0) if dur > 0 else 0.0
-        obs_trace.record_round(
-            rnd.source, stats, ops=rnd.ops, t_start=rnd.t_start,
-            phase_marks=[("issue", rnd.t_start), ("hidden", rnd.t_issued),
-                         ("commit", t_commit)])
+        with obs_trace.span("dht.flush"):
+            obs_trace.record_round(
+                rnd.source, stats, ops=rnd.ops, t_start=rnd.t_start,
+                phase_marks=[("issue", rnd.t_start),
+                             ("hidden", rnd.t_issued), ("commit", t_commit)])
         return rnd.outs + (stats,)
 
     def read_commit(self, rnd: ShardedRound):

@@ -36,7 +36,7 @@ bin/dispatch/apply/collect cycle *asynchronously* — JAX's async dispatch
 means every returned array is a future — and packages the results into
 an :class:`InFlightRound` handle; :func:`dht_commit` waits for the
 round's replies, resolves any pending-write forwards, and flushes the
-round's telemetry (with issue/commit phase spans and an ``overlap_frac``
+round's telemetry (with issue/hidden/commit marks and an ``overlap_frac``
 lane measuring what fraction of the round's latency the caller hid by
 doing other work between the two calls).  Because JAX chains dataflow
 through the returned ``state``, issuing round N+1 against round N's
@@ -621,7 +621,6 @@ class InFlightRound:
     rec: bool
     t_start: float
     t_issued: float
-    marks: list[tuple[str, float]]
     pending: Any = None
     conflict: Any = None          # np bool (n,) — forwarded rows
     keys_np: Any = None           # np uint32 (n, KW) — forward lookup keys
@@ -740,56 +739,54 @@ def dht_issue(
     rec = (obs_metrics.enabled() and axis_name is None
            and not isinstance(ops.keys, jax.core.Tracer)
            and not isinstance(state.keys, jax.core.Tracer))
-    t0 = time.perf_counter() if rec else 0.0
-    # replica-select lane (DESIGN.md §13): under k-successor replication
-    # the round's placement is the crash-tolerant first-live-replica
-    # select, and the count of items NOT served by their owner rides the
-    # stats as ``fallback_reads``.  Callers that precompute ``placement``
-    # (the L1 front end, the replicated write fan-out, repair) account
-    # for their own routing.
-    n_fallback = jnp.int32(0)
-    if (cfg.n_replicas > 1 and state.ring is not None
-            and placement is None and prev is None):
-        hashes = hash64(ops.keys) if hashes is None else hashes
-        dest_r, epoch_r, fb = replica_placement(state, hashes[0])
-        placement = (dest_r, epoch_r)
-        n_fallback = jnp.sum(ops.valid & fb).astype(jnp.int32)
-    elidable = (axis_name is not None and kinds == ("read",)
-                and prev is None and ops.op is None)
-    elide = elidable if elide_self is None else bool(elide_self)
-    assert not elide or elidable, (
-        "self-traffic elision needs a sharded uniform read round")
-    if elide:
-        hashes = hash64(ops.keys) if hashes is None else hashes
-        if placement is None:
-            placement = _owner_epoch(state, hashes[0])
-        my = _flat_axis_index(axis_name)
-        is_self = ops.valid & (placement[0] == my)
-        bin_valid = ops.valid & ~is_self
-    else:
-        is_self = None
-        bin_valid = ops.valid
+    t_start = time.perf_counter() if rec else 0.0
+    # Device-side phases (DESIGN.md §10): every op of a phase carries its
+    # name (obs.trace.PHASES) in its op_name metadata, on the eager and
+    # the shard_map path alike, so a profiler trace sums device time per
+    # phase.  Scopes change metadata only, never fusion.
+    with jax.named_scope("bin"):
+        # replica-select lane (DESIGN.md §13): under k-successor
+        # replication the round's placement is the crash-tolerant
+        # first-live-replica select, and the count of items NOT served by
+        # their owner rides the stats as ``fallback_reads``.  Callers that
+        # precompute ``placement`` (the L1 front end, the replicated write
+        # fan-out, repair) account for their own routing.
+        n_fallback = jnp.int32(0)
+        if (cfg.n_replicas > 1 and state.ring is not None
+                and placement is None and prev is None):
+            hashes = hash64(ops.keys) if hashes is None else hashes
+            dest_r, epoch_r, fb = replica_placement(state, hashes[0])
+            placement = (dest_r, epoch_r)
+            n_fallback = jnp.sum(ops.valid & fb).astype(jnp.int32)
+        elidable = (axis_name is not None and kinds == ("read",)
+                    and prev is None and ops.op is None)
+        elide = elidable if elide_self is None else bool(elide_self)
+        assert not elide or elidable, (
+            "self-traffic elision needs a sharded uniform read round")
+        if elide:
+            hashes = hash64(ops.keys) if hashes is None else hashes
+            if placement is None:
+                placement = _owner_epoch(state, hashes[0])
+            my = _flat_axis_index(axis_name)
+            is_self = ops.valid & (placement[0] == my)
+            bin_valid = ops.valid & ~is_self
+        else:
+            is_self = None
+            bin_valid = ops.valid
 
-    binned, base, _dest, used_prologue = _route_ops(
-        state, prev, ops, capacity, hashes, bin_valid, placement)
-    payload_valid = (ops.valid & binned.kept).astype(jnp.int32)
-    payloads = [base, ops.keys]
-    if do_write:
-        payloads.append(ops.vals.astype(jnp.uint32))
-    if ops.op is not None:
-        payloads.append(ops.op.astype(jnp.int32))
-    if prev is not None:
-        payloads.append(ops.esel.astype(jnp.int32))
-    payloads.append(payload_valid)
-    if rec:
-        # OBS_FENCE=1: block on each phase's products before the next
-        # mark so spans measure device time, not async issue time
-        obs_trace.fence(binned.pos, binned.kept, payloads)
-    t_dispatch = time.perf_counter() if rec else 0.0
-    inc = routing.dispatch(binned, payloads, axis_name)
-    if rec:
-        obs_trace.fence(inc)
-    t_apply = time.perf_counter() if rec else 0.0
+        binned, base, _dest, used_prologue = _route_ops(
+            state, prev, ops, capacity, hashes, bin_valid, placement)
+        payload_valid = (ops.valid & binned.kept).astype(jnp.int32)
+        payloads = [base, ops.keys]
+        if do_write:
+            payloads.append(ops.vals.astype(jnp.uint32))
+        if ops.op is not None:
+            payloads.append(ops.op.astype(jnp.int32))
+        if prev is not None:
+            payloads.append(ops.esel.astype(jnp.int32))
+        payloads.append(payload_valid)
+    with jax.named_scope("dispatch"):
+        inc = routing.dispatch(binned, payloads, axis_name)
 
     def _unpack(parts):
         it = iter(parts)
@@ -814,77 +811,74 @@ def dht_issue(
         return out
 
     prev_cfg = None if prev is None else prev.cfg
-    if axis_name is None:
-        slab = _slab_of(state)
-        if prev is not None:
-            rows = slab["meta"].shape[0]
-            pslab = {k: _pad_rows(v, rows) for k, v in _slab_of(prev).items()}
+    with jax.named_scope("apply"):
+        if axis_name is None:
+            slab = _slab_of(state)
+            if prev is not None:
+                rows = slab["meta"].shape[0]
+                pslab = {k: _pad_rows(v, rows)
+                         for k, v in _slab_of(prev).items()}
 
-            def handler(sl, psl, *parts):
-                b, k, v, o, e, m = _unpack(parts)
-                return _shard_apply(cfg, prev_cfg, sl, psl, b, k, v, o, e,
-                                    m.astype(bool), None, kinds)
+                def handler(sl, psl, *parts):
+                    b, k, v, o, e, m = _unpack(parts)
+                    return _shard_apply(cfg, prev_cfg, sl, psl, b, k, v, o,
+                                        e, m.astype(bool), None, kinds)
 
-            out = jax.vmap(handler)(slab, pslab, *inc)
+                out = jax.vmap(handler)(slab, pslab, *inc)
+            else:
+
+                def handler(sl, *parts):
+                    b, k, v, o, e, m = _unpack(parts)
+                    return _shard_apply(cfg, None, sl, None, b, k, v, o, e,
+                                        m.astype(bool), None, kinds)
+
+                out = jax.vmap(handler)(slab, *inc)
+            (slab, pslab, val, found, code, n_mm, rounds, tok,
+             gen, wpre, wpost) = out
+            n_mm, tok = jnp.sum(n_mm), jnp.sum(tok)
+            rounds = jnp.max(rounds)
         else:
+            slab = jax.tree.map(lambda x: x[0], _slab_of(state))
+            pslab = (None if prev is None
+                     else jax.tree.map(lambda x: x[0], _slab_of(prev)))
+            b, k, v, o, e, m = _unpack(inc)
+            if elide:
+                # self-owned requests ride the SAME _shard_apply call as
+                # extra rows after the incoming buffer — one pass,
+                # identical probe semantics, no collective
+                b = jnp.concatenate([b, base])
+                k = jnp.concatenate([k, ops.keys])
+                m = jnp.concatenate([m, is_self.astype(jnp.int32)])
+            (slab, pslab, val, found, code, n_mm, rounds, tok,
+             gen, wpre, wpost) = _shard_apply(
+                cfg, prev_cfg, slab, pslab, b, k, v, o, e,
+                m.astype(bool), axis_name, kinds)
+            buf_rows = binned.n_dest * binned.capacity
+            if elide:
+                val, val_l = val[:buf_rows], val[buf_rows:]
+                found, found_l = found[:buf_rows], found[buf_rows:]
+                code, code_l = code[:buf_rows], code[buf_rows:]
+                gen, gen_l = gen[:buf_rows], gen[buf_rows:]
+            slab = jax.tree.map(lambda x: x[None], slab)
+            if pslab is not None:
+                pslab = jax.tree.map(lambda x: x[None], pslab)
 
-            def handler(sl, *parts):
-                b, k, v, o, e, m = _unpack(parts)
-                return _shard_apply(cfg, None, sl, None, b, k, v, o, e,
-                                    m.astype(bool), None, kinds)
-
-            out = jax.vmap(handler)(slab, *inc)
-        (slab, pslab, val, found, code, n_mm, rounds, tok,
-         gen, wpre, wpost) = out
-        n_mm, tok = jnp.sum(n_mm), jnp.sum(tok)
-        rounds = jnp.max(rounds)
-        if rec:
-            obs_trace.fence(val, found, code)
-        t_collect = time.perf_counter() if rec else 0.0
-        coll = routing.collect(
-            binned, _replies(val, found, code, gen, wpre, wpost), None,
-            block_rows=l1_meta)
-    else:
-        slab = jax.tree.map(lambda x: x[0], _slab_of(state))
-        pslab = (None if prev is None
-                 else jax.tree.map(lambda x: x[0], _slab_of(prev)))
-        b, k, v, o, e, m = _unpack(inc)
-        if elide:
-            # self-owned requests ride the SAME _shard_apply call as extra
-            # rows after the incoming buffer — one pass, identical probe
-            # semantics, no collective
-            b = jnp.concatenate([b, base])
-            k = jnp.concatenate([k, ops.keys])
-            m = jnp.concatenate([m, is_self.astype(jnp.int32)])
-        (slab, pslab, val, found, code, n_mm, rounds, tok,
-         gen, wpre, wpost) = _shard_apply(
-            cfg, prev_cfg, slab, pslab, b, k, v, o, e,
-            m.astype(bool), axis_name, kinds)
-        buf_rows = binned.n_dest * binned.capacity
-        if elide:
-            val, val_l = val[:buf_rows], val[buf_rows:]
-            found, found_l = found[:buf_rows], found[buf_rows:]
-            code, code_l = code[:buf_rows], code[buf_rows:]
-            gen, gen_l = gen[:buf_rows], gen[buf_rows:]
-        slab = jax.tree.map(lambda x: x[None], slab)
-        if pslab is not None:
-            pslab = jax.tree.map(lambda x: x[None], pslab)
+    with jax.named_scope("collect"):
         coll = routing.collect(
             binned, _replies(val, found, code, gen, wpre, wpost), axis_name,
             block_rows=l1_meta)
-
-    items, blocks = coll if l1_meta else (coll, None)
-    val_b, found_b, code_b = items[0], items[1], items[2]
-    found_out = (found_b > 0) & ops.valid & binned.kept
-    code_out = jnp.where(ops.valid & binned.kept, code_b, W_DROPPED)
-    gen_out = items[3] if l1_meta else None
-    if elide:
-        found_out = jnp.where(is_self, found_l, found_out)
-        val_b = jnp.where(is_self[:, None], val_l, val_b)
-        code_out = jnp.where(is_self, code_l, code_out)
-        if l1_meta:
-            gen_out = jnp.where(is_self, gen_l, gen_out)
-    val_out = jnp.where(found_out[:, None], val_b, jnp.uint32(0))
+        items, blocks = coll if l1_meta else (coll, None)
+        val_b, found_b, code_b = items[0], items[1], items[2]
+        found_out = (found_b > 0) & ops.valid & binned.kept
+        code_out = jnp.where(ops.valid & binned.kept, code_b, W_DROPPED)
+        gen_out = items[3] if l1_meta else None
+        if elide:
+            found_out = jnp.where(is_self, found_l, found_out)
+            val_b = jnp.where(is_self[:, None], val_l, val_b)
+            code_out = jnp.where(is_self, code_l, code_out)
+            if l1_meta:
+                gen_out = jnp.where(is_self, gen_l, gen_out)
+        val_out = jnp.where(found_out[:, None], val_b, jnp.uint32(0))
     # wire accounting: both legs' buffer words + the padding fraction
     # (reply leg lanes: value words + found + code [+ 3 coherence lanes]),
     # plus the count-exchange prologue's histogram words (S counters each
@@ -945,7 +939,6 @@ def dht_issue(
         prev_out = _state_from(
             prev, {k2: v2[:prows] for k2, v2 in pslab.items()})
     mix = None
-    marks: list[tuple[str, float]] = []
     t_issued = 0.0
     if rec:
         if ops.op is None:
@@ -959,14 +952,12 @@ def dht_issue(
             # forwarded rows were masked out of the probe but are still
             # this round's logical traffic
             mix["read"] = mix.get("read", 0) + int(conflict.sum())
-        marks = [("bin", t0), ("dispatch", t_dispatch),
-                 ("apply", t_apply), ("collect", t_collect)]
         t_issued = time.perf_counter()
     return InFlightRound(
         state=state_out, prev=prev_out, vals=val_out, found=found_out,
         code=code_out, estats=estats, kinds=kinds,
         source=source or ("engine." + "+".join(kinds)), mix=mix, rec=rec,
-        t_start=t0, t_issued=t_issued, marks=marks,
+        t_start=t_start, t_issued=t_issued,
         pending=pending, conflict=conflict, keys_np=keys_np)
 
 
@@ -982,11 +973,12 @@ def dht_commit(
     are device-complete (eager only — under a trace this is a no-op and
     the pair degenerates to the classic fused round), and flushes the
     round's telemetry with two extra ingredients over the synchronous
-    path: a ``commit`` phase span, and ``issue_us`` / ``hidden_us`` /
-    ``commit_wait_us`` / ``overlap_frac`` stat lanes.  ``hidden_us`` is
-    the host time spent *elsewhere* between issue returning and commit
-    being called — latency the caller successfully overlapped;
-    ``overlap_frac`` is its share of the round's total duration.
+    path: ``issue``/``hidden``/``commit`` marks, and ``issue_us`` /
+    ``hidden_us`` / ``commit_wait_us`` / ``overlap_frac`` stat lanes.
+    ``hidden_us`` is the host time spent *elsewhere* between issue
+    returning and commit being called — latency the caller successfully
+    overlapped; ``overlap_frac`` is its share of the round's total
+    duration.
 
     Returns the classic engine tuple
     ``(state', prev', vals, found, code, estats)``.
@@ -1017,7 +1009,8 @@ def dht_commit(
             stats["forwarded"] = n_fwd
         obs_trace.record_round(
             rnd.source, stats, ops=rnd.mix, t_start=rnd.t_start,
-            phase_marks=rnd.marks + [("commit", t_commit)])
+            phase_marks=[("issue", rnd.t_start), ("hidden", rnd.t_issued),
+                         ("commit", t_commit)])
     return rnd.state, rnd.prev, vals, found, code, rnd.estats
 
 

@@ -161,6 +161,7 @@ def shard_apply_pallas(
         _apply_kernel, n_probe=n_probe, validate_checksum=validate_checksum)
     val, found, wsel, wkind = pl.pallas_call(
         kernel,
+        name="shard_apply_pallas",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((c, vw), jnp.uint32),

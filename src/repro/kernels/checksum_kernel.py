@@ -37,6 +37,7 @@ def checksum_pallas(
     vals_p = jnp.pad(vals, ((0, n_pad - n), (0, 0)))
     out = pl.pallas_call(
         _checksum_kernel,
+        name="checksum_pallas",
         grid=(n_pad // BLOCK_N,),
         in_specs=[
             pl.BlockSpec((BLOCK_N, kw), lambda i: (i, 0)),

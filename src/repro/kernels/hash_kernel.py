@@ -39,6 +39,7 @@ def hash64_pallas(keys: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
     keys_p = jnp.pad(keys, ((0, n_pad - n), (0, 0)))
     out = pl.pallas_call(
         _hash_kernel,
+        name="hash64_pallas",
         grid=(n_pad // BLOCK_N,),
         in_specs=[pl.BlockSpec((BLOCK_N, kw), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((BLOCK_N, 2), lambda i: (i, 0)),

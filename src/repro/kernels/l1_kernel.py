@@ -87,6 +87,7 @@ def l1_probe_pallas(
     )
     out = pl.pallas_call(
         _l1_kernel,
+        name="l1_probe_pallas",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, vw + 1), jnp.uint32),
         interpret=interpret,

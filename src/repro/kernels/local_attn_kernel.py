@@ -100,6 +100,7 @@ def local_attention_pallas(
         _kernel, window=window, bq=bq, bk=bk, causal=causal)
     return pl.pallas_call(
         kernel,
+        name="local_attention_pallas",
         grid=(bh, s // bq, band_tiles),
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),

@@ -103,6 +103,7 @@ def probe_pallas(
     kernel = functools.partial(_probe_kernel, validate_checksum=validate_checksum)
     val, found = pl.pallas_call(
         kernel,
+        name="probe_pallas",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((c, vw), jnp.uint32),

@@ -39,6 +39,7 @@ def round_sig_pallas(
     rows = tiled.shape[0]
     out = pl.pallas_call(
         functools.partial(_round_kernel, sig_digits=sig_digits),
+        name="round_sig_pallas",
         grid=(rows // BLOCK_R,),
         in_specs=[pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i: (i, 0)),

@@ -22,7 +22,10 @@ the last two dims of a block, and a (1, L) block of an (n, L) array is
 neither (8, 128)-aligned nor the full array, so the matrices ride a
 squeezed leading row axis: array (n, 1, L), block (None, 1, L).  Validated
 bit-for-bit against ``kernels/ref.ref_route_pack``/``ref_route_unpack``
-(pinned to the production jnp path in ``core/routing.py``).
+(pinned to the production jnp path in ``core/routing.py``).  Each
+``pallas_call`` carries its wrapper's name (``name=``), which becomes the
+kernel's HLO instruction name, so profiler traces find the kernels by
+that name whatever wraps them.
 """
 from __future__ import annotations
 
@@ -74,6 +77,7 @@ def route_pack_pallas(
     )
     out = pl.pallas_call(
         _pack_kernel,
+        name="route_pack_pallas",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, 1, width), jnp.uint32),
         interpret=interpret,
@@ -123,6 +127,7 @@ def route_unpack_pallas(
     )
     out = pl.pallas_call(
         _unpack_kernel,
+        name="route_unpack_pallas",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, width), jnp.uint32),
         interpret=interpret,

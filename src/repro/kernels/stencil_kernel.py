@@ -104,6 +104,7 @@ def stencil_keys_pallas(
         key_words=key_words, span=span)
     keys, base = pl.pallas_call(
         kernel,
+        name="stencil_keys_pallas",
         grid=(n_pad // BLOCK_R,),
         in_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0))],
         out_specs=[

@@ -4,8 +4,10 @@ cost model, skew diagnostics, and regression gating.
 - :mod:`repro.obs.metrics` — counters / gauges / fixed-bucket histograms
   with deterministic snapshots and cross-shard merge.
 - :mod:`repro.obs.trace`  — bounded ring buffer of per-round events,
-  JSONL + Chrome ``trace_event`` export; ``OBS_FENCE=1`` fences phase
-  spans with ``block_until_ready``.
+  JSONL + Chrome ``trace_event`` export; :func:`~repro.obs.trace.span`,
+  the host spans the ``ShardedDHT`` wrappers open in the profiler's trace,
+  and the ``gc`` span that marks full garbage collections there
+  (installed on import).
 - :mod:`repro.obs.report` — ``python -m repro.obs.report`` CLI rendering
   a round timeline, top-metrics summary, and ``--skew`` imbalance view.
 - :mod:`repro.obs.costmodel` — calibrated α-β round-cost model fitted
@@ -21,8 +23,10 @@ from . import costmodel, metrics, skew, trace
 from .metrics import (counter_value, counting, disabled, enabled,
                       get_registry, inc, merge_snapshots, merge_wire_stats,
                       observe, set_enabled, set_gauge)
-from .trace import (count_traced_rounds, fence, fence_enabled, get_tracer,
-                    record_event, record_round, set_fence)
+from .trace import (count_traced_rounds, get_tracer, install_gc_span,
+                    record_event, record_round, span)
+
+install_gc_span()
 
 
 def __getattr__(name):
@@ -39,6 +43,6 @@ __all__ = [
     "counter_value", "counting", "disabled",
     "enabled", "get_registry", "inc", "merge_snapshots",
     "merge_wire_stats", "observe", "set_enabled", "set_gauge",
-    "count_traced_rounds", "fence", "fence_enabled", "get_tracer",
-    "record_event", "record_round", "set_fence",
+    "count_traced_rounds", "get_tracer", "record_event", "record_round",
+    "span",
 ]

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import bisect
 import json
+import math
 import os
 from typing import Iterable, Sequence
 
@@ -76,26 +77,55 @@ class disabled:
         return False
 
 
+def _add_exact(partials: list[float], x: float) -> None:
+    """Add ``x`` to ``partials``, non-overlapping floats whose exact sum
+    is the running total (Shewchuk's algorithm, the one ``math.fsum``
+    runs), so that the total does not depend on the order of the adds."""
+    if partials and not math.isfinite(partials[-1]):
+        partials[-1] += x          # inf/nan absorb every later value
+        return
+    if not math.isfinite(x):
+        partials[:] = [x]          # ... and every earlier finite one
+        return
+    i = 0
+    for y in partials:
+        if abs(x) < abs(y):
+            x, y = y, x
+        hi = x + y
+        lo = y - (hi - x)
+        if lo:
+            partials[i] = lo
+            i += 1
+        x = hi
+    partials[i:] = [x]
+
+
 class Histogram:
     """Fixed-bucket histogram: ``edges`` are ascending bucket upper
     bounds; value v lands in the first bucket with ``v <= edge`` (one
     overflow bucket past the last edge).  Tracks sum/count/min/max for
-    exact means alongside the bucketed shape."""
+    exact means alongside the bucketed shape.  The sum is kept as exact
+    partials and reported correctly rounded, so it is the same under any
+    order of observations and merges."""
 
-    __slots__ = ("edges", "counts", "total", "count", "vmin", "vmax")
+    __slots__ = ("edges", "counts", "partials", "count", "vmin", "vmax")
 
     def __init__(self, edges: Sequence[float] = LATENCY_EDGES_US):
         self.edges = tuple(float(e) for e in edges)
         self.counts = [0] * (len(self.edges) + 1)
-        self.total = 0.0
+        self.partials: list[float] = []
         self.count = 0
         self.vmin = float("inf")
         self.vmax = float("-inf")
 
+    @property
+    def total(self) -> float:
+        return math.fsum(self.partials)
+
     def observe(self, value: float) -> None:
         v = float(value)
         self.counts[bisect.bisect_left(self.edges, v)] += 1
-        self.total += v
+        _add_exact(self.partials, v)
         self.count += 1
         self.vmin = min(self.vmin, v)
         self.vmax = max(self.vmax, v)
@@ -104,13 +134,16 @@ class Histogram:
         return self.total / self.count if self.count else 0.0
 
     def merge(self, other: "Histogram") -> "Histogram":
-        """Pure merge (self unchanged).  Elementwise count addition —
-        associative and commutative because the edges are fixed."""
+        """Pure merge (self unchanged).  Elementwise count addition and
+        an exact sum — associative and commutative because the edges are
+        fixed."""
         if self.edges != other.edges:
             raise ValueError("cannot merge histograms with different edges")
         out = Histogram(self.edges)
         out.counts = [a + b for a, b in zip(self.counts, other.counts)]
-        out.total = self.total + other.total
+        out.partials = list(self.partials)
+        for p in other.partials:
+            _add_exact(out.partials, p)
         out.count = self.count + other.count
         out.vmin = min(self.vmin, other.vmin)
         out.vmax = max(self.vmax, other.vmax)
@@ -130,7 +163,7 @@ class Histogram:
     def from_dict(cls, d: dict) -> "Histogram":
         h = cls(d["edges"])
         h.counts = [int(c) for c in d["counts"]]
-        h.total = float(d["sum"])
+        _add_exact(h.partials, float(d["sum"]))
         h.count = int(d["count"])
         h.vmin = float("inf") if d.get("min") is None else float(d["min"])
         h.vmax = float("-inf") if d.get("max") is None else float(d["max"])

@@ -1,42 +1,39 @@
-"""Per-round trace recorder — bounded ring buffer of structured events.
+"""Per-round trace recorder and the program's spans on the profiler's clock.
 
 Every *executed* engine round (eager ``dht_execute``, each jitted call a
 ``ShardedDHT`` wrapper makes, each ``migration_step`` batch) lands one
-:class:`RoundEvent` here via :func:`record_round`, carrying the phase
-spans (bin / dispatch / apply / collect), the op mix, and every scalar
-stat lane of the round (wire words both legs, fill fraction, capacity
-vs. load, L1 hits, lock-retry rounds, epoch/watermark stamps — whatever
-the round's ``estats`` held).  The ring is bounded
+:class:`RoundEvent` here via :func:`record_round`, carrying the op mix
+and every scalar stat lane of the round (wire words both legs, fill
+fraction, capacity vs. load, L1 hits, lock-retry rounds, epoch/watermark
+stamps — whatever the round's ``estats`` held).  The ring is bounded
 (``OBS_TRACE_MAXLEN``, default 4096 events) so long benchmark loops
 cannot grow host memory without bound.
 
 Exports: :meth:`TraceRecorder.to_jsonl` (one JSON object per line, the
 schema in DESIGN.md §10) and :meth:`TraceRecorder.to_chrome_trace`
 (Chrome ``trace_event`` JSON — load the file in https://ui.perfetto.dev
-to see rounds and their phase spans on a timeline).
+to see rounds on a timeline).
 
 jit-safety: :func:`record_round` is host-only.  The engine calls it only
 on the eager path (no tracers in sight); under ``jit``/``shard_map`` the
 stat lanes ride the return value and the *caller's* host code (e.g. the
-``ShardedDHT`` wrappers) records them.  Phase spans are host
-``perf_counter`` marks around the engine's issue points; the event's
-total ``dur`` is measured *after* the stat lanes are fetched, so it
-includes the device work those scalars depend on.
+``ShardedDHT`` wrappers) records them.  The event's ``dur`` is measured
+*after* the stat lanes are fetched, so it includes the device work those
+scalars depend on.
 
-Phase-span caveat (and the ``OBS_FENCE=1`` switch): JAX dispatch is
-asynchronous, so by default a phase span measures the host time to
-*issue* that phase's work, not the device time to run it — the un-issued
-remainder piles into whichever phase happens to force a value (usually
-the final ``dur``, which fetches the stat lanes).  Setting ``OBS_FENCE=1``
-in the environment (or :func:`set_fence`) makes the engine
-``block_until_ready`` on each phase's products before taking the next
-mark, so spans measure device time — at the cost of serializing the
-pipeline, which perturbs the very timing being measured.  The default is
-therefore non-perturbing; fence only when reading phase breakdowns.
+Where the time goes is not measured here but in the profiler's own trace
+(``jax.profiler.trace``), on the one clock that the device ops share
+(DESIGN.md §10): :func:`span` opens a named host span there (the
+``ShardedDHT`` wrappers' ``dht.*`` spans), :func:`install_gc_span` marks
+Python's full garbage collections as ``gc`` spans, and the op engine
+wraps its phases in ``jax.named_scope`` (:data:`PHASES`), so every device
+op carries its phase in its ``op_name`` metadata.  With no profiler
+attached a span costs one flag check in C++.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import os
 import time
@@ -46,35 +43,44 @@ from typing import Sequence
 from . import metrics
 
 __all__ = ["RoundEvent", "TraceRecorder", "get_tracer", "record_round",
-           "record_event", "count_traced_rounds", "PHASES",
-           "fence_enabled", "set_fence", "fence"]
+           "record_event", "count_traced_rounds", "PHASES", "span",
+           "install_gc_span"]
 
-PHASES = ("bin", "dispatch", "apply", "collect", "commit")
-
-_FENCE = os.environ.get("OBS_FENCE", "0") in ("1", "true", "yes")
-
-
-def fence_enabled() -> bool:
-    """Are phase marks fenced with ``block_until_ready``?
-    (``OBS_FENCE=1`` starts it on; default off = non-perturbing.)"""
-    return _FENCE
+# the op engine's device-side phases, each a ``jax.named_scope`` around
+# its ops (``op_engine.dht_issue``)
+PHASES = ("bin", "dispatch", "apply", "collect")
 
 
-def set_fence(on: bool) -> bool:
-    """Toggle phase fencing; returns the previous state (for restore)."""
-    global _FENCE
-    prev, _FENCE = _FENCE, bool(on)
-    return prev
+def span(name: str):
+    """A host span named ``name`` in the profiler's trace, on the clock
+    of the device ops: ``with span("dht.flush"): ...``.  No arguments are
+    attached, so the trace shows the name as given."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
-def fence(*values) -> None:
-    """Barrier before a phase mark: when fencing is on, block until the
-    given arrays (the previous phase's products) are device-complete, so
-    the span measures device time rather than async issue time."""
-    if _FENCE:
-        import jax
+_gc_span = None              # the ``gc`` span of the running collection
 
-        jax.block_until_ready(values)
+
+def _gc_callback(phase: str, info: dict) -> None:
+    global _gc_span
+    if info["generation"] != 2:
+        return
+    if phase == "start":
+        _gc_span = span("gc")
+        _gc_span.__enter__()
+    elif _gc_span is not None:
+        _gc_span.__exit__(None, None, None)
+        _gc_span = None
+
+
+def install_gc_span() -> None:
+    """Mark each of Python's full (generation 2) garbage collections as a
+    ``gc`` span in the profiler's trace (once per process)."""
+    if _gc_callback not in gc.callbacks:
+        gc.callbacks.append(_gc_callback)
+
 
 # estats lanes -> registry counters (plain additive flush).
 _COUNTER_LANES = {

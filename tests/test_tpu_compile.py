@@ -9,6 +9,7 @@ The topology is described inside a module fixture (never at import), so
 only the worker that runs this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -155,3 +156,25 @@ def test_sharded_round_compiles_for_v5e(topo, chip_kernels, round_kind):
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes)
     assert used < V5E_HBM, used
+
+
+@pytest.mark.parametrize("round_kind", ["write", "read", "mixed"])
+def test_route_kernels_keep_their_names_in_a_round(topo, chip_kernels,
+                                                   round_kind):
+    """Inside a whole round the route kernels' HLO instructions are still
+    named ``route_pack_pallas*`` / ``route_unpack_pallas*`` (their
+    ``pallas_call`` names), which is how profiler traces find them."""
+    d = _described_table(topo)
+    keys, vals = _batch(d, d.cfg.key_words), _batch(d, d.cfg.val_words)
+    valid = _batch(d, None, jnp.bool_)
+    if round_kind == "write":
+        low = d.write_fn().lower(d.state, keys, vals, valid)
+    elif round_kind == "read":
+        low = d.read_fn().lower(d.state, keys, valid)
+    else:
+        low = d.execute_fn(("read", "write")).lower(
+            d.state, keys, vals, valid, _batch(d, None, jnp.int32))
+    names = {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_0-9]+?)(?:\.\d+)? = [^\n]*custom_call_target="
+        r"\"tpu_custom_call\"", low.compile().as_text())}
+    assert {"route_pack_pallas", "route_unpack_pallas"} <= names, names
