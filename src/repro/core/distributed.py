@@ -55,6 +55,16 @@ def _psum_stats(stats: dict, axes) -> dict:
     return out
 
 
+def _with_retry_rows(stats: dict, valid) -> dict:
+    """Add the ``retry_rows`` lane to a write round's stats: the valid
+    rows this device's round left ``W_DROPPED`` (routing overflow, or no
+    free bucket after ``n_probe`` passes), the rows the write wrapper
+    re-issues.  Routing's ``dropped`` lane misses the latter."""
+    stats["retry_rows"] = jnp.sum(
+        valid & (stats["code"] == dht_ops.W_DROPPED), dtype=jnp.int32)
+    return stats
+
+
 def _state_shardings(mesh: Mesh, template: DHTState):
     """NamedShardings for a DHTState: slabs spread over the mesh, the
     membership ring (if any) replicated on every device."""
@@ -186,12 +196,13 @@ class ShardedDHT:
         def fn(state, keys, vals, valid):
             state, stats = dht_ops.dht_write(
                 state, keys, vals, valid, axis_name=axes)
-            return state, _psum_stats(stats, axes)
+            return state, _psum_stats(_with_retry_rows(stats, valid), axes)
 
         stats_spec = {k: (batch_spec if k == "code" else P())
                       for k in ("inserted", "updated", "evicted", "dropped",
                                 "rounds", "lock_tokens", "epoch",
-                                "wire_words", "fill_frac", "code")
+                                "wire_words", "fill_frac", "code",
+                                "retry_rows")
                       + SKEW_KEYS}
         return jax.jit(
             shard_map(
@@ -337,12 +348,14 @@ class ShardedDHT:
             l1d = jax.tree.map(lambda x: x[0], l1)
             l1d = l1cache.with_shard_wmarks(l1d, stats.pop("wmark_post"))
             l1 = jax.tree.map(lambda x: x[None], l1d)
-            return state, l1, _psum_stats(stats, axes)
+            return state, l1, _psum_stats(_with_retry_rows(stats, valid),
+                                          axes)
 
         stats_spec = {k: (batch_spec if k == "code" else P())
                       for k in ("inserted", "updated", "evicted", "dropped",
                                 "rounds", "lock_tokens", "epoch",
-                                "wire_words", "fill_frac", "code")
+                                "wire_words", "fill_frac", "code",
+                                "retry_rows")
                       + SKEW_KEYS}
         return jax.jit(
             shard_map(
@@ -356,7 +369,8 @@ class ShardedDHT:
     # -- k-successor replication (DESIGN.md §13) ---------------------------
     _WRITE_REP_KEYS = ("inserted", "updated", "evicted", "dropped",
                        "rounds", "lock_tokens", "epoch", "wire_words",
-                       "fill_frac", "code", "acked", "replica_writes")
+                       "fill_frac", "code", "acked", "replica_writes",
+                       "retry_rows")
 
     def write_replicated_fn(self, state: DHTState | None = None):
         """Replicated write round (``dht.dht_write_replicated``): every
@@ -372,7 +386,7 @@ class ShardedDHT:
         def fn(state, keys, vals, valid):
             state, stats = dht_ops.dht_write_replicated(
                 state, keys, vals, valid, axis_name=axes)
-            return state, _psum_stats(stats, axes)
+            return state, _psum_stats(_with_retry_rows(stats, valid), axes)
 
         stats_spec = {k: (batch_spec if k == "code" else P())
                       for k in self._WRITE_REP_KEYS + SKEW_KEYS}
@@ -396,7 +410,8 @@ class ShardedDHT:
             l1d = jax.tree.map(lambda x: x[0], l1)
             l1d = l1cache.with_shard_wmarks(l1d, stats.pop("wmark_post"))
             l1 = jax.tree.map(lambda x: x[None], l1d)
-            return state, l1, _psum_stats(stats, axes)
+            return state, l1, _psum_stats(_with_retry_rows(stats, valid),
+                                          axes)
 
         stats_spec = {k: (batch_spec if k == "code" else P())
                       for k in self._WRITE_REP_KEYS + SKEW_KEYS}
@@ -515,7 +530,12 @@ class ShardedDHT:
         §13: traced auto-capacity can under-provision a skewed round, and
         a silently dropped insert is a lost acked write).  Only the FINAL
         round's unrecovered drops stay on the ``dropped``/
-        ``engine.dropped`` lanes; recovered rows count as ``requeued``."""
+        ``engine.dropped`` lanes; recovered rows count as ``requeued``.
+
+        Each round moves its scalar stat lanes to the host in one fetch,
+        started at dispatch; the retry is decided from that fetch's
+        ``retry_rows`` lane, so a round without drops makes no other
+        transfer in either direction."""
         with obs_trace.span("dht.write"):
             valid = self._ones(keys.shape[0]) if valid is None else valid
             n_ops = int(keys.shape[0])
@@ -525,20 +545,23 @@ class ShardedDHT:
                 t_a = time.perf_counter()
                 with obs_trace.span("dht.dispatch"):
                     stats = self._write_dispatch(keys, vals, valid)
+                    obs_trace.start_fetch(stats)
                 code = stats["code"]
-                with obs_trace.span("dht.retry_check"):
-                    retry = valid & (code == dht_ops.W_DROPPED)
-                    n_retry = int(jnp.sum(retry))
-                final = n_retry == 0 or attempt >= max_retries
-                flush = dict(stats)
-                if not final:
-                    # this round's drops are about to be re-issued — flush
-                    # them as requeued so engine.dropped keeps meaning
-                    # "lost for good" (what the CI ratio gate measures)
-                    flush["requeued"] = flush.pop("dropped")
                 with obs_trace.span("dht.flush"):
-                    obs_trace.record_round("sharded.write", flush,
+                    lanes = obs_trace.fetch_lanes(stats)
+                    obs_trace.record_round("sharded.write", lanes,
                                            ops={"write": n_ops}, t_start=t_a)
+                with obs_trace.span("dht.retry_check"):
+                    n_retry = lanes["retry_rows"]
+                    final = n_retry == 0 or attempt >= max_retries
+                    if not final:
+                        # this round's drops are about to be re-issued —
+                        # move them dropped -> requeued so engine.dropped
+                        # keeps meaning "lost for good" (what the CI ratio
+                        # gate measures)
+                        obs_metrics.inc("engine.dropped", -lanes["dropped"])
+                        obs_metrics.inc("engine.requeued", lanes["dropped"])
+                        retry = valid & (code == dht_ops.W_DROPPED)
                 if total is None:
                     total = dict(stats)
                 else:
@@ -551,8 +574,9 @@ class ShardedDHT:
                     total["code"] = jnp.where(code != dht_ops.W_DROPPED,
                                               code, total["code"])
                     total["dropped"] = stats["dropped"]
+                    total["retry_rows"] = stats["retry_rows"]
                 if final:
-                    total["write_retries"] = jnp.int32(attempt)
+                    total["write_retries"] = np.int32(attempt)
                     return total
                 attempt += 1
                 n_ops = n_retry
@@ -574,6 +598,7 @@ class ShardedDHT:
                     self.state, vals, found, stats = fn(self.state, keys,
                                                         valid)
                     source = "sharded.read"
+                obs_trace.start_fetch(stats)
             with obs_trace.span("dht.flush"):
                 obs_trace.record_round(source, stats,
                                        ops={"read": int(keys.shape[0])},
@@ -596,6 +621,7 @@ class ShardedDHT:
                     fn = self._cached_fn("read_many", self.read_many_fn)
                     self.state, vals, found, stats = fn(self.state, keys,
                                                         valid)
+                obs_trace.start_fetch(stats)
             with obs_trace.span("dht.flush"):
                 obs_trace.record_round(
                     "sharded.read_many", stats,
@@ -607,9 +633,9 @@ class ShardedDHT:
     # The jitted closures are asynchronous already — a call returns device
     # futures immediately — so the issue half is simply "call and don't
     # fetch".  The sync wrappers above fetch eagerly when they flush the
-    # stat lanes to the registry (int()/record_round force the scalars);
-    # these defer that fetch to the commit half, letting the caller run
-    # compute (or issue the next round) against the in-flight collective.
+    # stat lanes to the registry (record_round's one batched fetch); these
+    # defer that fetch to the commit half, letting the caller run compute
+    # (or issue the next round) against the in-flight collective.
 
     def read_async(self, keys, valid=None) -> ShardedRound:
         """Issue a read round without waiting; pair with
@@ -629,6 +655,7 @@ class ShardedDHT:
                                      extra=self._async_key())
                 self.state, vals, found, stats = fn(self.state, keys, valid)
                 source = "sharded.read"
+            obs_trace.start_fetch(stats)
         return ShardedRound(source=source, outs=(vals, found), stats=stats,
                             ops={"read": int(keys.shape[0])}, t_start=t0,
                             t_issued=time.perf_counter())
@@ -641,6 +668,7 @@ class ShardedDHT:
         with obs_trace.span("dht.dispatch"):
             stats = self._write_dispatch(keys, vals, valid,
                                          extra=self._async_key())
+            obs_trace.start_fetch(stats)
         # no retry loop here — it would force a mid-pipeline fetch; the
         # pipelined caller re-issues dropped rows itself (the surrogate
         # driver retires only non-dropped keys from its PendingWrites)

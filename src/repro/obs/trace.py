@@ -43,8 +43,8 @@ from typing import Sequence
 from . import metrics
 
 __all__ = ["RoundEvent", "TraceRecorder", "get_tracer", "record_round",
-           "record_event", "count_traced_rounds", "PHASES", "span",
-           "install_gc_span"]
+           "record_event", "start_fetch", "fetch_lanes",
+           "count_traced_rounds", "PHASES", "span", "install_gc_span"]
 
 # the op engine's device-side phases, each a ``jax.named_scope`` around
 # its ops (``op_engine.dht_issue``)
@@ -194,21 +194,39 @@ def get_tracer() -> TraceRecorder:
     return _TRACER
 
 
-def _scalarize(stats: dict) -> dict:
-    """Fetch the scalar stat lanes as plain Python numbers (one pass;
-    non-scalar lanes like watermark vectors are skipped)."""
-    import numpy as np
+def start_fetch(stats: dict) -> None:
+    """Start the device-to-host copy of each scalar stat lane.  Called at
+    dispatch, the copies queue behind the round's program, so the flush's
+    :func:`fetch_lanes` finds the values on the host when the round ends."""
+    import jax
 
-    out = {}
+    for v in stats.values():
+        if isinstance(v, jax.Array) and v.ndim == 0:
+            v.copy_to_host_async()
+
+
+def fetch_lanes(stats: dict) -> dict:
+    """The scalar stat lanes as Python numbers, in one device-to-host
+    fetch.  Each lane's shape is read before any transfer: vector lanes
+    (``code``, ``bin_counts``, watermark vectors) are never fetched, and
+    Python numbers pass through unchanged.  The 0-d device lanes go to
+    the host in one ``jax.device_get``, which starts every copy before it
+    waits on any; ``engine.host_fetches`` counts these fetches."""
+    import jax
+
+    scal = {}
     for k, v in stats.items():
-        try:
-            a = np.asarray(v)
-        except Exception:
-            continue
-        if a.ndim != 0 or a.dtype.kind not in "biuf":
-            continue
-        out[k] = a.item()
-    return out
+        if isinstance(v, (bool, int, float)):
+            scal[k] = v
+        elif getattr(v, "shape", None) == ():
+            if getattr(getattr(v, "dtype", None), "kind", "O") in "biuf":
+                scal[k] = v
+    dev = {k: v for k, v in scal.items() if isinstance(v, jax.Array)}
+    if dev:
+        metrics.inc("engine.host_fetches")
+        scal.update(jax.device_get(dev))
+    return {k: v.item() if hasattr(v, "item") else v
+            for k, v in scal.items()}
 
 
 def record_round(source: str, stats: dict, *, ops: dict | None = None,
@@ -218,16 +236,17 @@ def record_round(source: str, stats: dict, *, ops: dict | None = None,
     """Flush one executed round: trace event + registry accumulation.
 
     ``stats`` is the round's stat-lane dict (jax scalars fine — fetched
-    here, once).  ``phase_marks`` is ``[(phase, start_time), ...]`` in
-    order; each phase ends where the next begins, the last at record
-    time.  ``engine.rounds`` advances by the round's ``dispatch_rounds``
-    lane (default 1) — this is the host-side executed-round counter that
-    jit trace-caching cannot defeat.  ``dur`` overrides the measured
+    here in one :func:`fetch_lanes`; host numbers pass through).
+    ``phase_marks`` is ``[(phase, start_time), ...]`` in order; each
+    phase ends where the next begins, the last at record time.
+    ``engine.rounds`` advances by the round's ``dispatch_rounds`` lane
+    (default 1) — this is the host-side executed-round counter that jit
+    trace-caching cannot defeat.  ``dur`` overrides the measured
     duration — for callers that timed the round externally (e.g. a
     benchmark recording a median-of-k jitted call as one event)."""
     if not metrics.enabled():
         return
-    scal = _scalarize(stats)
+    scal = fetch_lanes(stats)
     now = time.perf_counter()
     ts = t_start if t_start is not None else now
     if dur is None:
@@ -294,7 +313,7 @@ def record_event(source: str, stats: dict | None = None, *,
     ts = t_start if t_start is not None else now
     _TRACER.record(RoundEvent(
         source=source, ts=ts, dur=max(now - ts, 0.0), spans={},
-        ops=dict(ops or {}), stats=_scalarize(stats or {})))
+        ops=dict(ops or {}), stats=fetch_lanes(stats or {})))
 
 
 def count_traced_rounds(fn, *args) -> int:

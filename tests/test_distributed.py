@@ -326,3 +326,57 @@ def test_dryrun_entry_smallest_cell():
               round(cell["memory"].get("temp_bytes", 0) / 1e9, 2), "GB temp")
     """, devices=512)
     print(out)
+
+
+def test_sharded_write_retry_rows_lane_decides_the_retry():
+    """Forced routing overflow (tiny static capacity): each write round's
+    ``retry_rows`` lane equals the host count of its valid rows left
+    ``W_DROPPED``, exactly those rows are re-issued, and the final codes
+    hold no drop — for the single-copy and the replicated closures."""
+    print(_run("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro import obs
+        from repro.core import DHTConfig, ring_create
+        from repro.core.dht import W_DROPPED
+        from repro.core.distributed import ShardedDHT, make_mesh_1d
+
+        mesh = make_mesh_1d(8)
+        rng = np.random.default_rng(7)
+        n = 2048
+        keys = jnp.asarray(rng.integers(0, 2**31, size=(n, 20)), jnp.uint32)
+        vals = jnp.asarray(rng.integers(0, 2**31, size=(n, 26)), jnp.uint32)
+        for replicas in (1, 2):
+            d = ShardedDHT.create(
+                mesh, DHTConfig(n_shards=8, n_replicas=replicas,
+                                buckets_per_shard=4096, capacity=24),
+                ring=ring_create(8))
+            rounds = []
+            dispatch = d._write_dispatch
+
+            def recorded(k, v, valid, extra=()):
+                stats = dispatch(k, v, valid, extra)
+                rounds.append((np.asarray(valid), np.asarray(stats["code"]),
+                               int(stats["retry_rows"])))
+                return stats
+
+            d._write_dispatch = recorded
+            c0 = obs.get_registry().snapshot()["counters"]
+            ws = d.write(keys, vals)
+            c1 = obs.get_registry().snapshot()["counters"]
+            assert len(rounds) >= 2, (replicas, len(rounds))
+            for i, (valid, code, retry_rows) in enumerate(rounds):
+                left = valid & (code == W_DROPPED)
+                assert retry_rows == int(left.sum()), (replicas, i)
+                if i + 1 < len(rounds):
+                    assert retry_rows > 0
+                    assert (rounds[i + 1][0] == left).all(), (replicas, i)
+            assert rounds[-1][2] == 0, replicas
+            assert not (np.asarray(ws["code"]) == W_DROPPED).any(), replicas
+            assert int(ws["retry_rows"]) == 0 and int(ws["dropped"]) == 0
+            assert int(ws["write_retries"]) == len(rounds) - 1
+            delta = {k: c1.get(k, 0) - c0.get(k, 0)
+                     for k in ("engine.rounds", "engine.host_fetches")}
+            assert delta["engine.rounds"] == len(rounds), delta
+            assert delta["engine.host_fetches"] == len(rounds), delta
+            print("retry_rows OK", replicas, [r[2] for r in rounds])
+    """))

@@ -296,3 +296,113 @@ def test_eager_round_records_issue_hidden_commit_marks(fresh_registry):
     ev = obs.get_tracer().events()[-1]
     assert list(ev.spans) == ["issue", "hidden", "commit"]
     assert ev.spans["issue"][0] == ev.ts
+
+
+# -- one batched device-to-host fetch per round ---------------------------
+
+class _VectorLane:
+    """A vector lane that must never be transferred: reading it fails."""
+
+    shape = (4,)
+    ndim = 1
+    dtype = np.dtype(np.int32)
+
+    def __array__(self, *a, **k):
+        raise AssertionError("a vector lane was fetched")
+
+
+@pytest.fixture()
+def device_gets(monkeypatch):
+    """Every ``jax.device_get`` call, as the ``ndim`` of each leaf."""
+    calls = []
+    real = jax.device_get
+
+    def counted(x):
+        calls.append([np.ndim(leaf) for leaf in jax.tree.leaves(x)])
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", counted)
+    return calls
+
+
+def _sharded_table():
+    from repro.core.distributed import ShardedDHT, make_mesh_1d
+
+    d = ShardedDHT.create(make_mesh_1d(1), DHTConfig(
+        n_shards=1, buckets_per_shard=256, key_words=4, val_words=3,
+        capacity=64))
+    rng = np.random.default_rng(3)
+    keys = jnp.asarray(rng.integers(0, 2**31, size=(64, 4)), jnp.uint32)
+    vals = jnp.asarray(rng.integers(0, 2**31, size=(64, 3)), jnp.uint32)
+    return d, keys, vals
+
+
+def _one_round(d, call, keys, vals):
+    if call == "write":
+        return d.write(keys, vals)
+    if call == "read":
+        return d.read(keys)[2]
+    if call == "read_many":
+        return d.read_many(keys.reshape(16, 4, 4))[2]
+    if call == "write_async":
+        return d.write_commit(d.write_async(keys, vals))
+    return d.read_commit(d.read_async(keys))[2]
+
+
+@pytest.mark.parametrize("call", ["write", "read", "read_many",
+                                  "write_async", "read_async"])
+def test_sharded_round_makes_one_scalar_fetch(fresh_registry, device_gets,
+                                              call):
+    """A ``ShardedDHT`` round moves its stat lanes to the host in exactly
+    one ``jax.device_get``, and only 0-d lanes ride it; the caller's
+    ``code``/``dropped`` lanes stay device arrays."""
+    d, keys, vals = _sharded_table()
+    _one_round(d, call, keys, vals)            # compile outside the count
+    device_gets.clear()
+    stats = _one_round(d, call, keys, vals)
+    assert len(device_gets) == 1, device_gets
+    assert device_gets[0] and set(device_gets[0]) == {0}, device_gets
+    assert isinstance(stats["dropped"], jax.Array)
+    if call == "write":
+        assert isinstance(stats["code"], jax.Array)
+        assert stats["code"].shape == (64,)
+        assert int(stats["write_retries"]) == 0
+
+
+def test_record_round_never_fetches_a_vector_lane(fresh_registry,
+                                                  device_gets):
+    """The lane's shape is read before any transfer: a vector lane whose
+    ``__array__`` raises is skipped, the scalar lanes still flush in one
+    fetch, and Python numbers pass through unchanged."""
+    stats = {"wire_words": jnp.int32(96), "dropped": jnp.int32(2),
+             "fill_frac": jnp.float32(0.5), "bin_counts": _VectorLane(),
+             "code": jnp.zeros((8,), jnp.int32), "issue_us": 12.5,
+             "forwarded": 3}
+    obs.record_round("unit.vector", stats, ops={"write": 8})
+    assert len(device_gets) == 1 and device_gets[0] == [0, 0, 0]
+    assert fresh_registry.counter("engine.wire_words") == 96
+    assert fresh_registry.counter("engine.dropped") == 2
+    assert fresh_registry.counter("engine.host_fetches") == 1
+    ev = obs.get_tracer().events()[-1]
+    assert ev.stats == {"wire_words": 96, "dropped": 2, "fill_frac": 0.5,
+                        "issue_us": 12.5, "forwarded": 3}
+
+
+def test_record_round_of_host_numbers_makes_no_fetch(fresh_registry,
+                                                     device_gets):
+    obs.record_round("unit.host", {"wire_words": np.int32(5), "hits": 2})
+    assert device_gets == []
+    assert fresh_registry.counter("engine.host_fetches") == 0
+    assert fresh_registry.counter("engine.rounds") == 1
+    assert fresh_registry.counter("engine.wire_words") == 5
+
+
+def test_host_fetches_equal_rounds_on_the_served_path(fresh_registry):
+    d, keys, vals = _sharded_table()
+    for _ in range(3):
+        d.write(keys, vals)
+        d.read(keys)
+    d.read_many(keys.reshape(16, 4, 4))
+    c = fresh_registry.snapshot()["counters"]
+    assert c["engine.rounds"] == 7, c
+    assert c["engine.host_fetches"] == c["engine.rounds"], c

@@ -46,7 +46,7 @@ def _host_events(log_dir, names):
 
 
 @pytest.mark.parametrize("call,children", [
-    ("write", ("dht.dispatch", "dht.retry_check", "dht.flush")),
+    ("write", ("dht.dispatch", "dht.flush", "dht.retry_check")),
     ("read", ("dht.dispatch", "dht.flush")),
     ("read_many", ("dht.dispatch", "dht.flush")),
 ])
